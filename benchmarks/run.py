@@ -17,6 +17,7 @@ from benchmarks.fig5 import run_fig5
 from benchmarks.fig6 import run_fig6
 from benchmarks.table2 import run_table2
 from benchmarks.table5 import run_table5
+from repro.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -98,6 +99,7 @@ def main() -> None:
                     help="with --specialize-only: expect a --smoke "
                          "matrix artifact (the CI smoke job)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
 

@@ -10,6 +10,15 @@ __all__ = ["pagerank_np", "sssp_np", "cc_np", "bc_np", "bfs_np",
            "is_proper_coloring"]
 
 
+def _segment_min_into(out, vals, row_ptr):
+    """``out[i] = min(out[i], vals[row_ptr[i]:row_ptr[i+1]].min())`` for
+    every non-empty row of a sorted edge order."""
+    nz = np.flatnonzero(np.diff(row_ptr))
+    if nz.size:
+        out[nz] = np.minimum(out[nz], np.minimum.reduceat(vals, row_ptr[nz]))
+    return out
+
+
 def bfs_np(g: Graph, source=0):
     """Level-synchronous BFS depths; -1 for unreachable vertices."""
     v = g.n_nodes
@@ -17,16 +26,18 @@ def bfs_np(g: Graph, source=0):
     col = np.asarray(g.dst, np.int64)
     depth = np.full(v, -1, np.int32)
     depth[source] = 0
-    frontier = [source]
-    while frontier:
-        nxt = []
-        for u in frontier:
-            for e in range(row_ptr[u], row_ptr[u + 1]):
-                t = col[e]
-                if depth[t] == -1:
-                    depth[t] = depth[u] + 1
-                    nxt.append(t)
-        frontier = nxt
+    frontier = np.asarray([source], np.int64)
+    level = 0
+    while frontier.size:
+        starts = row_ptr[frontier]
+        counts = row_ptr[frontier + 1] - starts
+        # every out-edge of the frontier, as positions in the CSR order
+        offs = np.cumsum(counts) - counts
+        edges = np.repeat(starts - offs, counts) + np.arange(counts.sum())
+        nbr = np.unique(col[edges])
+        frontier = nbr[depth[nbr] == -1]
+        level += 1
+        depth[frontier] = level
     return depth
 
 
@@ -39,8 +50,7 @@ def pagerank_np(g: Graph, damping=0.85, tol=1e-6, max_iters=256):
     inv = 1.0 / np.maximum(out_deg, 1)
     dangling = out_deg == 0
     for _ in range(max_iters):
-        contrib = np.zeros(v)
-        np.add.at(contrib, dst, rank[src] * inv[src])
+        contrib = np.bincount(dst, weights=rank[src] * inv[src], minlength=v)
         dm = rank[dangling].sum()
         new = (1 - damping) / v + damping * (contrib + dm / v)
         if np.abs(new - rank).sum() < tol:
@@ -53,15 +63,13 @@ def pagerank_np(g: Graph, damping=0.85, tol=1e-6, max_iters=256):
 def sssp_np(g: Graph, source=0):
     """Bellman-Ford (graphs are symmetric; no negative weights)."""
     v = g.n_nodes
-    src = np.asarray(g.src, np.int64)
-    dst = np.asarray(g.dst, np.int64)
-    w = np.asarray(g.weight, np.float64)
+    src = np.asarray(g.src_in, np.int64)   # by-dst order: dst sorted
+    row_ptr = np.asarray(g.row_ptr_in, np.int64)
+    w = np.asarray(g.weight_in, np.float64)
     dist = np.full(v, np.inf)
     dist[source] = 0.0
     for _ in range(v):
-        cand = dist[src] + w
-        new = dist.copy()
-        np.minimum.at(new, dst, cand)
+        new = _segment_min_into(dist.copy(), dist[src] + w, row_ptr)
         if np.array_equal(new, dist, equal_nan=True):
             break
         dist = new
@@ -71,14 +79,15 @@ def sssp_np(g: Graph, source=0):
 def cc_np(g: Graph):
     """Min-vertex-id component labels via BFS union."""
     v = g.n_nodes
-    src = np.asarray(g.src, np.int64)
+    src_in = np.asarray(g.src_in, np.int64)
+    row_ptr_in = np.asarray(g.row_ptr_in, np.int64)
     dst = np.asarray(g.dst, np.int64)
+    row_ptr_out = np.asarray(g.row_ptr_out, np.int64)
     label = np.arange(v)
     changed = True
     while changed:
-        new = label.copy()
-        np.minimum.at(new, dst, label[src])
-        np.minimum.at(new, src, label[dst])
+        new = _segment_min_into(label.copy(), label[src_in], row_ptr_in)
+        new = _segment_min_into(new, label[dst], row_ptr_out)
         new = new[new]  # pointer jump
         changed = not np.array_equal(new, label)
         label = new

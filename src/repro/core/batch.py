@@ -65,8 +65,8 @@ import numpy as np
 
 from repro.core.config_space import SystemConfig, UpdateProp
 from repro.core.executor import (EdgeContext, RunResult, STATS,
-                                 _cached_exec_fn, _normalize_autotune,
-                                 _trace_flags)
+                                 _cached_exec_fn, _jit_hoisted,
+                                 _normalize_autotune, _trace_flags)
 from repro.core.frontier import ALPHA, choose_direction_batch
 from repro.core.plan_cache import PLAN_CACHE
 from repro.core.vertex_program import (FRONTIER_DIR_KEY, FRONTIER_OCC_KEY,
@@ -711,10 +711,9 @@ def run_fused_batch(program: VertexProgram, batch: GraphBatch,
              jnp.zeros((B,), bool), db, ob))
 
     def build():
-        fn = jax.jit(fused, donate_argnums=(0, 1, 2))
-        if warmup:
-            fn = fn.lower(state, dir_buf, occ_buf).compile()
-        return program, fn
+        return program, _jit_hoisted(fused, (state, dir_buf, occ_buf),
+                                     donate_argnums=(0, 1, 2),
+                                     compile=warmup)
 
     fn = _cached_exec_fn(
         program, bctx.inner,
@@ -845,11 +844,9 @@ def run_batch_slice(program: VertexProgram, batch: GraphBatch,
             (st, jnp.int32(0), it_b, jnp.zeros((B,), bool), db, ob))
 
     def build():
-        fn = jax.jit(sliced, donate_argnums=(0, 4, 5))
-        if warmup:
-            fn = fn.lower(state, it_b, done_b0, limit_b,
-                          dir_buf, occ_buf).compile()
-        return program, fn
+        return program, _jit_hoisted(
+            sliced, (state, it_b, done_b0, limit_b, dir_buf, occ_buf),
+            donate_argnums=(0, 4, 5), compile=warmup)
 
     fn = _cached_exec_fn(
         program, bctx.inner,

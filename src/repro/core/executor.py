@@ -222,6 +222,11 @@ class EdgeContext:
         self.sparse_edge_capacity = int(sparse_edge_capacity)
         self._sparse_vertex_capacity = max(
             1, min(self.n_nodes, self.sparse_edge_capacity))
+        # a device scalar, so occupancy divides at run time (correctly
+        # rounded) as the batched context's per-graph [B] capacities do,
+        # never by a compile-time constant the compiler may turn into a
+        # reciprocal multiply
+        self._cap_e_f32 = jnp.float32(self.sparse_edge_capacity)
         self._row_ptr_out = g.row_ptr_out
         self._csr_raw = (g.src, g.dst, g.weight)
         n_chunks = 1 if config.consistency is Consistency.DRF0 \
@@ -506,7 +511,7 @@ class EdgeContext:
             fits = ~front.overflowed & ~edges.overflowed
             occ = jnp.where(
                 fits,
-                edges.count.astype(jnp.float32) / self.sparse_edge_capacity,
+                edges.count.astype(jnp.float32) / self._cap_e_f32,
                 dense_occ)
             out = jax.lax.cond(
                 fits,
@@ -702,17 +707,47 @@ def _cached_exec_fn(program: VertexProgram, ctx: EdgeContext,
                           capacity=_EXEC_FN_CAPACITY)[1]
 
 
+def _jit_hoisted(fn: Callable, args: tuple, donate_argnums: tuple = (),
+                 compile: bool = False) -> Callable:
+    """``jax.jit(fn)`` with the arrays ``fn`` closes over passed to the
+    executable as arguments instead of embedded in it.
+
+    A jitted closure compiles every device array it captures into its
+    executable as a constant.  The runners close over an
+    :class:`EdgeContext`, so each would carry the graph's edge arrays
+    through the compiler: at Graph500 scale 20 about 1 GB per runner,
+    minutes of compile and tens of GB of host memory.  ``fn`` is traced
+    once on ``args`` to find what it captures; the returned callable
+    takes the same arguments as ``fn`` (``compile=True`` compiles it
+    ahead of time for those arguments' shapes).
+    """
+    closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args)
+    consts = [jnp.asarray(c) for c in closed.consts]
+    out_tree = jax.tree.structure(out_shape)
+
+    def call(consts, *a):
+        out = jax.core.eval_jaxpr(closed.jaxpr, consts, *jax.tree.leaves(a))
+        return jax.tree.unflatten(out_tree, out)
+
+    jitted = jax.jit(call,
+                     donate_argnums=tuple(i + 1 for i in donate_argnums))
+    if compile:
+        jitted = jitted.lower(consts, *args).compile()
+    return partial(jitted, consts)
+
+
 def _run_host(program: VertexProgram, ctx: EdgeContext, state,
               limit: int, warmup: bool) -> RunResult:
     """Kernel-per-iteration oracle engine: one jitted dispatch per step
     plus a blocking convergence read between steps."""
 
     def build():
-        @partial(jax.jit, donate_argnums=(0,))
-        def step(st, it):
+        def step_fn(st, it):
             new = program.step(ctx, st, it)
             done = program.converged(st, new)
             return new, done
+        step = _jit_hoisted(step_fn, (state, jnp.int32(0)),
+                            donate_argnums=(0,))
         if warmup:  # compile outside the timed region (paper times
             # kernels only).  `step` donates its input, so warm the jit
             # cache on a copy.  Inside build(): a cached step is already
@@ -795,15 +830,13 @@ def _run_fused(program: VertexProgram, ctx: EdgeContext, state,
             (st, jnp.int32(0), jnp.asarray(False), db, ob))
 
     def build():
-        fn = jax.jit(fused, donate_argnums=(0, 1, 2))
-        if warmup:
-            # AOT-compile outside the timed region; unlike the host
-            # engine's run-one-step warmup this executes nothing on
-            # device.  The compiled executable is cached per (program,
-            # context, limit) so sweep repeats skip the while_loop
-            # compile entirely.
-            fn = fn.lower(state, dir_buf, occ_buf).compile()
-        return program, fn
+        # warmup AOT-compiles outside the timed region; unlike the host
+        # engine's run-one-step warmup this executes nothing on device.
+        # The compiled executable is cached per (program, context,
+        # limit) so sweep repeats skip the while_loop compile entirely.
+        return program, _jit_hoisted(fused, (state, dir_buf, occ_buf),
+                                     donate_argnums=(0, 1, 2),
+                                     compile=warmup)
 
     fn = _cached_exec_fn(program, ctx,
                          ("fused", limit, traced, occ_traced), build)

@@ -59,8 +59,8 @@ import numpy as np
 
 from repro.core.config_space import SystemConfig, UpdateProp
 from repro.core.executor import (EdgeContext, RunResult, STATS,
-                                 _cached_exec_fn, _normalize_autotune,
-                                 _trace_flags)
+                                 _cached_exec_fn, _jit_hoisted,
+                                 _normalize_autotune, _trace_flags)
 from repro.core.vertex_program import (DENSE_OCC, FRONTIER_DIR_KEY,
                                        FRONTIER_OCC_KEY, VertexProgram,
                                        dense_occupancy)
@@ -294,13 +294,14 @@ def check_certificate(program: VertexProgram, ctx: EdgeContext,
     if program.certificate is None:
         return None
 
+    state = jax.tree.map(jnp.asarray, state)
+
     def build():
-        fn = jax.jit(lambda st: jnp.asarray(
-            program.certificate(ctx, st), bool).reshape(()))
-        return program, fn
+        return program, _jit_hoisted(lambda st: jnp.asarray(
+            program.certificate(ctx, st), bool).reshape(()), (state,))
 
     fn = _cached_exec_fn(program, ctx, ("certificate",), build)
-    return bool(fn(jax.tree.map(jnp.asarray, state)))
+    return bool(fn(state))
 
 
 # ----------------------------------------------------------------------
@@ -371,11 +372,10 @@ def _fused_segment_fn(program, ctx, state, limit, traced, occ_traced,
         return st2, it2, done2, db2, ob2, flags
 
     def build():
-        fn = jax.jit(fused_seg, donate_argnums=(0, 3, 4))
-        if warmup:
-            fn = fn.lower(state, jnp.int32(0), jnp.asarray(False),
-                          dir_buf, occ_buf, jnp.int32(0)).compile()
-        return program, fn
+        return program, _jit_hoisted(
+            fused_seg, (state, jnp.int32(0), jnp.asarray(False), dir_buf,
+                        occ_buf, jnp.int32(0)),
+            donate_argnums=(0, 3, 4), compile=warmup)
 
     names = tuple(n for n, _ in sentinel_fns)
     return _cached_exec_fn(
@@ -404,14 +404,13 @@ def _sentinel_eval_fn(program, ctx, limit, occ_traced, sentinel_fns):
 def _host_step_fn(program, ctx, state, warmup):
     """The host engine's cached per-iteration step (same cache entry as
     :func:`repro.core.executor._run_host` builds)."""
-    from functools import partial
-
     def build():
-        @partial(jax.jit, donate_argnums=(0,))
-        def step(st, it):
+        def step_fn(st, it):
             new = program.step(ctx, st, it)
             done = program.converged(st, new)
             return new, done
+        step = _jit_hoisted(step_fn, (state, jnp.int32(0)),
+                            donate_argnums=(0,))
         if warmup:
             copy = jax.tree.map(lambda x: x.copy(), state)
             jax.block_until_ready(step(copy, jnp.int32(0)))

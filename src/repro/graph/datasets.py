@@ -39,6 +39,7 @@ the stand-in <-> real swap is auditable.
 from __future__ import annotations
 
 import os
+import zlib
 from functools import lru_cache
 from pathlib import Path
 
@@ -116,7 +117,8 @@ def paper_graph(name: str, scale: int = 1, weighted: bool = False,
     v, e, max_deg, avg_deg = PAPER_STATS[name][:4]
     n = max(4 * block_size, v // scale)
     ne = max(n * 2, e // scale)
-    seed = hash(name) % (2**31)
+    # crc32, not hash(): str hashes are salted per process
+    seed = zlib.crc32(name.encode()) % (2**31)
     if name == "AMZ":      # skewed but degree-ordered ids -> warp maxes
         # homogeneous within each tile -> Imbalance L (like the real input)
         return powerlaw_graph(n, ne // 2, alpha=1.2, max_degree=max_deg,

@@ -263,8 +263,8 @@ def suggest_plan(features: Dict[str, float],
 # ---------------------------------------------------------------------------
 # reducer construction + measurement
 # ---------------------------------------------------------------------------
-def build_reducer(graph, order: str, plan: Optional[TilingPlan] = None,
-                  interpret: bool = True) -> BlockedSegmentReducer:
+def build_reducer(graph, order: str,
+                  plan: Optional[TilingPlan] = None) -> BlockedSegmentReducer:
     """Build the blocked reducer for one edge order under ``plan``.
 
     The single construction path shared by the executor and the tuner,
@@ -277,7 +277,7 @@ def build_reducer(graph, order: str, plan: Optional[TilingPlan] = None,
         dst_owned = np.asarray(graph.dst)[np.asarray(graph.perm_owned)]
         return BlockedSegmentReducer.from_plan(
             dst_owned, np.asarray(graph.block_ptr), v, graph.block_size,
-            plan, interpret=interpret)
+            plan)
     if order == "pull":
         # The CSC order is fully dst-sorted, so it is binned under ANY
         # block partition — the plan's effective block size (coarsened
@@ -290,7 +290,7 @@ def build_reducer(graph, order: str, plan: Optional[TilingPlan] = None,
         pull_ptr = np.asarray(graph.row_ptr_in)[bounds]
         return BlockedSegmentReducer(
             np.asarray(graph.dst_in), pull_ptr, v, eff_bs,
-            tile_e=plan.tile_e, interpret=interpret, plan=plan)
+            tile_e=plan.tile_e, plan=plan)
     raise ValueError(f"unknown blocked order {order!r}")
 
 

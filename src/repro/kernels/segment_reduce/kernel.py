@@ -7,14 +7,21 @@ it ("ownership registration at L1"), accumulated locally, and written back
 to HBM exactly once — versus the LLC-analogue global XLA scatter that
 resolves every update at HBM.
 
-Sum uses the canonical TPU trick: scatter-within-block == one-hot matmul on
-the MXU (contrib = onehot(local_ids)^T @ values).  Min/max use a masked
-VPU reduce over a feature tile.
+Every array is lane-dense: an edge tile is ``rows`` rows of 128 edges
+(:func:`tile_rows`), and the output keeps the segments of a block on
+lanes.  Sum uses the canonical TPU trick: scatter-within-block ==
+one-hot matmul on the MXU (contrib = values_row @ onehot^T, one row of
+128 edges at a time).  Min/max mask a ``[block, 128]`` tile per row on
+the VPU, fold the rows elementwise, transpose, and finish across
+sublanes.
 
-Grid: one step per edge tile; ``tile_block_id`` (scalar-prefetched) steers
-the output BlockSpec so Pallas keeps the same VMEM block resident across
-consecutive tiles of one block. ``tile_first`` zeroes the accumulator when
-a new block begins.
+Grid: one step per (feature, edge tile); ``tile_block_id``
+(scalar-prefetched) steers the output BlockSpec so Pallas keeps the same
+VMEM block resident across consecutive tiles of one block.
+``tile_first`` initialises the accumulator when a new block begins.
+Interpret mode follows the backend: interpreted on the CPU, compiled
+by Mosaic on the TPU.  Output blocks must be a multiple of 128 segments
+to compile for the TPU.
 """
 from __future__ import annotations
 
@@ -26,7 +33,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["seg_sum_pallas", "seg_minmax_pallas", "plan_tiles"]
+__all__ = ["seg_sum_pallas", "seg_minmax_pallas", "plan_tiles", "tile_rows"]
 
 
 def plan_tiles(block_ptr: np.ndarray, tile_e: int):
@@ -74,118 +81,152 @@ def plan_tiles(block_ptr: np.ndarray, tile_e: int):
     return (gather, tbid, tfirst)
 
 
+#: TPU vector lane count: the minor dimension of every kernel block.
+LANES = 128
+
+
+def tile_rows(tile_e: int) -> tuple:
+    """``(rows, lanes)`` of one edge tile in the lane-dense layout.
+
+    A tile of ``tile_e`` edges is laid out as ``rows`` rows of ``lanes``
+    edges, the edge axis on the 128-lane minor dimension.  Tiles whose
+    width is not a multiple of 128 (tiny test plans) are one row of the
+    whole tile, still a legal TPU block because it spans the array.
+    """
+    if tile_e % LANES == 0:
+        return tile_e // LANES, LANES
+    return 1, tile_e
+
+
+def _interpret(interpret):
+    """Pallas interpret mode chosen by the backend: interpreted on the
+    CPU, compiled by Mosaic on the TPU, refused elsewhere."""
+    if interpret is not None:
+        return bool(interpret)
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise NotImplementedError(
+        f"the blocked segment reducers run on 'cpu' (interpreted) or "
+        f"'tpu' (compiled), not on {backend!r}")
+
+
+def _blocked_call(kernel, vals_tiled, lids_tiled, tile_block_id, tile_first,
+                  block_size, num_out_blocks, interpret):
+    """One grid step per (feature, edge tile); returns ``[D, 1, V_pad]``.
+
+    Feature j is the OUTER grid axis and edge tile i the INNER one, so
+    revisits of one output block happen on consecutive grid steps (the
+    Pallas revisit contract).  Ids are ``[n_tiles, rows, lanes]`` and
+    values ``[D, n_tiles, rows, lanes]``; the output keeps segments on
+    lanes, so no array of the call has a minor dimension of 1.
+    """
+    d, n_tiles, rows, lanes = vals_tiled.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(d, n_tiles),
+        in_specs=[
+            pl.BlockSpec((1, rows, lanes),
+                         lambda j, i, tbid, tfirst: (i, 0, 0)),
+            pl.BlockSpec((1, 1, rows, lanes),
+                         lambda j, i, tbid, tfirst: (j, i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, block_size),
+                               lambda j, i, tbid, tfirst: (j, 0, tbid[i])),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((d, 1, num_out_blocks * block_size),
+                                       vals_tiled.dtype),
+        interpret=_interpret(interpret),
+    )(tile_block_id, tile_first, lids_tiled, vals_tiled)
+
+
 # ---------------------------------------------------------------------------
 # sum kernel (MXU one-hot matmul)
 # ---------------------------------------------------------------------------
 def _sum_kernel(tbid_ref, tfirst_ref, lid_ref, vals_ref, out_ref):
-    i = pl.program_id(0)
+    i = pl.program_id(1)
 
     @pl.when(tfirst_ref[i] == 1)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    lids = lid_ref[0, :]                       # [tile_e] local ids, -1 pad
-    vals = vals_ref[0]                         # [tile_e, D]
-    tile_e = lids.shape[0]
-    block = out_ref.shape[0]
-    onehot = (lids[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (tile_e, block), 1)).astype(vals.dtype)
-    contrib = jax.lax.dot_general(
-        onehot, vals,
-        dimension_numbers=(((0,), (0,)), ((), ())),  # onehot^T @ vals
-        preferred_element_type=jnp.float32)
-    out_ref[...] += contrib.astype(out_ref.dtype)
+    lids = lid_ref[0]                          # [rows, lanes], -1 pad
+    vals = vals_ref[0, 0]                      # [rows, lanes]
+    rows, lanes = lids.shape
+    block = out_ref.shape[-1]
+    seg = jax.lax.broadcasted_iota(jnp.int32, (block, lanes), 0)
+    acc = jnp.zeros((1, block), jnp.float32)
+    for r in range(rows):
+        onehot = (seg == lids[r:r + 1, :]).astype(vals.dtype)
+        acc += jax.lax.dot_general(            # vals_r @ onehot^T
+            vals[r:r + 1, :], onehot,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    out_ref[0] += acc.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "num_out_blocks",
                                              "interpret"))
-def seg_sum_pallas(vals_tiled: jnp.ndarray,   # [n_tiles, tile_e, D]
-                   lids_tiled: jnp.ndarray,   # [n_tiles, tile_e]
+def seg_sum_pallas(vals_tiled: jnp.ndarray,   # [D, n_tiles, rows, lanes]
+                   lids_tiled: jnp.ndarray,   # [n_tiles, rows, lanes]
                    tile_block_id: jnp.ndarray,
                    tile_first: jnp.ndarray,
                    *, block_size: int, num_out_blocks: int,
-                   interpret: bool = True) -> jnp.ndarray:
-    n_tiles, tile_e, d = vals_tiled.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, tile_e), lambda i, tbid, tfirst: (i, 0)),
-            pl.BlockSpec((1, tile_e, d), lambda i, tbid, tfirst: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_size, d),
-                               lambda i, tbid, tfirst: (tbid[i], 0)),
-    )
+                   interpret=None) -> jnp.ndarray:
+    """Blocked segment sum, ``[D, 1, num_out_blocks * block_size]``.
 
-    return pl.pallas_call(
-        _sum_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_out_blocks * block_size, d),
-                                       vals_tiled.dtype),
-        interpret=interpret,
-    )(tile_block_id, tile_first, lids_tiled, vals_tiled)
+    ``interpret=None`` lets the backend decide (see :func:`_interpret`).
+    """
+    return _blocked_call(_sum_kernel, vals_tiled, lids_tiled, tile_block_id,
+                         tile_first, block_size, num_out_blocks, interpret)
 
 
 # ---------------------------------------------------------------------------
-# min/max kernel (masked VPU reduce, feature-tiled)
+# min/max kernel (masked VPU reduce)
 # ---------------------------------------------------------------------------
 def _minmax_kernel(tbid_ref, tfirst_ref, lid_ref, vals_ref, out_ref, *,
                    is_min: bool, ident):
-    i = pl.program_id(1)  # edge-tile index (innermost: consecutive revisits)
+    i = pl.program_id(1)
+    comb = jnp.minimum if is_min else jnp.maximum
 
     @pl.when(tfirst_ref[i] == 1)
     def _init():
         out_ref[...] = jnp.full_like(out_ref, ident)
 
-    lids = lid_ref[0, :]
-    vals = vals_ref[0]                          # [tile_e, bd]
-    tile_e = lids.shape[0]
-    block = out_ref.shape[0]
-    onehot = lids[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (tile_e, block), 1)
-    masked = jnp.where(onehot[:, :, None], vals[:, None, :], ident)
-    red = masked.min(axis=0) if is_min else masked.max(axis=0)
-    cur = out_ref[...]
-    out_ref[...] = jnp.minimum(cur, red) if is_min else jnp.maximum(cur, red)
+    lids = lid_ref[0]
+    vals = vals_ref[0, 0]
+    rows, lanes = lids.shape
+    block = out_ref.shape[-1]
+    seg = jax.lax.broadcasted_iota(jnp.int32, (block, lanes), 0)
+    # [block, lanes] partials: segment on sublanes, edge lane on lanes
+    part = None
+    for r in range(rows):
+        m = jnp.where(seg == lids[r:r + 1, :], vals[r:r + 1, :], ident)
+        part = m if part is None else comb(part, m)
+    # transpose so segments lie on lanes, then finish across sublanes
+    red = part.T
+    red = (red.min(axis=0, keepdims=True) if is_min
+           else red.max(axis=0, keepdims=True))
+    out_ref[0] = comb(out_ref[0], red)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "num_out_blocks",
-                                             "is_min", "interpret", "bd"))
+                                             "is_min", "interpret"))
 def seg_minmax_pallas(vals_tiled, lids_tiled, tile_block_id, tile_first, *,
                       block_size: int, num_out_blocks: int, is_min: bool,
-                      bd: int = 8, interpret: bool = True) -> jnp.ndarray:
-    n_tiles, tile_e, d = vals_tiled.shape
-    n_d = -(-d // bd)
-    if n_d * bd != d:
-        pad = n_d * bd - d
-        vals_tiled = jnp.pad(vals_tiled, ((0, 0), (0, 0), (0, pad)))
+                      interpret=None) -> jnp.ndarray:
+    """Blocked segment min/max; layouts as :func:`seg_sum_pallas`."""
     dtype = vals_tiled.dtype
     if jnp.issubdtype(dtype, jnp.floating):
         ident = float("inf") if is_min else float("-inf")
     else:
         ident = int(jnp.iinfo(dtype).max if is_min else jnp.iinfo(dtype).min)
-
-    # feature tile j is OUTER, edge tile i INNER so revisits of one output
-    # block happen on consecutive grid steps (Pallas revisit contract).
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_d, n_tiles),
-        in_specs=[
-            pl.BlockSpec((1, tile_e), lambda j, i, tbid, tfirst: (i, 0)),
-            pl.BlockSpec((1, tile_e, bd),
-                         lambda j, i, tbid, tfirst: (i, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_size, bd),
-                               lambda j, i, tbid, tfirst: (tbid[i], j)),
-    )
-
     kernel = functools.partial(_minmax_kernel, is_min=is_min, ident=ident)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_out_blocks * block_size, n_d * bd),
-                                       dtype),
-        interpret=interpret,
-    )(tile_block_id, tile_first, lids_tiled, vals_tiled)
-    return out[:, :d]
+    return _blocked_call(kernel, vals_tiled, lids_tiled, tile_block_id,
+                         tile_first, block_size, num_out_blocks, interpret)

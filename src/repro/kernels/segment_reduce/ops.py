@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.segment_reduce.kernel import (plan_tiles, seg_minmax_pallas,
-                                                 seg_sum_pallas)
+                                                 seg_sum_pallas, tile_rows)
 
 __all__ = ["BlockedSegmentReducer", "TilingPlan", "DEFAULT_PLAN",
            "coarsen_block_ptr", "bin_edges_by_block"]
@@ -136,7 +136,7 @@ class BlockedSegmentReducer:
 
     def __init__(self, segment_ids: np.ndarray, block_ptr: np.ndarray,
                  num_segments: int, block_size: int, tile_e: int = 512,
-                 interpret: bool = True, plan: "TilingPlan | None" = None):
+                 plan: "TilingPlan | None" = None):
         self.plan = plan if plan is not None else TilingPlan(tile_e=tile_e)
         # int32 end to end: the kernels index with int32, and the plan's
         # [n_tiles, tile_e] arrays are the dominant host/device index
@@ -147,24 +147,25 @@ class BlockedSegmentReducer:
             block_ptr, tile_e)
         self.n_tiles = int(self.gather_idx.shape[0])
         self.tile_e = int(tile_e)
+        self.rows, self.lanes = tile_rows(self.tile_e)
         pad = self.gather_idx < 0
         safe = np.where(pad, np.int32(0), self.gather_idx)
         lids = ids[safe] - self.tile_block_id[:, None] * np.int32(block_size)
-        self.lids = jnp.asarray(np.where(pad, np.int32(-1), lids))
-        self.gather = jnp.asarray(safe)
-        self.pad_mask = jnp.asarray(pad)
+        # lane-dense [n_tiles, rows, lanes]: the edge axis on lanes
+        self.lids = jnp.asarray(np.where(pad, np.int32(-1), lids).reshape(
+            self.n_tiles, self.rows, self.lanes))
+        self.gather = jnp.asarray(safe.reshape(-1))
+        self.pad_mask = jnp.asarray(pad.reshape(-1))
         self.tbid = jnp.asarray(self.tile_block_id)
         self.tfirst = jnp.asarray(self.tile_first)
         self.num_segments = int(num_segments)
         self.block_size = int(block_size)
         self.num_out_blocks = -(-int(num_segments) // int(block_size))
-        self.interpret = bool(interpret)
 
     @classmethod
     def from_plan(cls, segment_ids: np.ndarray, block_ptr: np.ndarray,
                   num_segments: int, base_block_size: int,
-                  plan: "TilingPlan | None" = None,
-                  interpret: bool = True) -> "BlockedSegmentReducer":
+                  plan: "TilingPlan | None" = None) -> "BlockedSegmentReducer":
         """Plan-parameterized constructor (the autotuner entry point).
 
         ``block_ptr``/``base_block_size`` describe the edge order's
@@ -185,25 +186,30 @@ class BlockedSegmentReducer:
                              "per-vertex row offsets instead")
         return cls(segment_ids, coarsen_block_ptr(block_ptr, plan.block_mult),
                    num_segments, base_block_size * plan.block_mult,
-                   tile_e=plan.tile_e, interpret=interpret, plan=plan)
+                   tile_e=plan.tile_e, plan=plan)
 
-    def _tile_values(self, values: jnp.ndarray, fill) -> jnp.ndarray:
+    def _tile_values(self, values: jnp.ndarray, fill):
+        """Gather ``[E]`` or ``[E, D]`` values into the kernels'
+        lane-dense ``[D, n_tiles, rows, lanes]`` tiles; padding slots
+        hold ``fill``."""
         squeeze = values.ndim == 1
-        if squeeze:
-            values = values[:, None]
-        tiled = jnp.take(values, self.gather.reshape(-1), axis=0)
-        tiled = tiled.reshape(*self.gather.shape, values.shape[-1])
-        tiled = jnp.where(self.pad_mask[..., None], fill, tiled)
+        rows_t = values[None, :] if squeeze else values.T      # [D, E]
+        tiled = jnp.take(rows_t, self.gather, axis=1)
+        tiled = jnp.where(self.pad_mask[None, :], fill, tiled)
+        tiled = tiled.reshape(rows_t.shape[0], self.n_tiles, self.rows,
+                              self.lanes)
         return tiled, squeeze
+
+    def _finish(self, out, squeeze):
+        out = out[:, 0, :self.num_segments]                    # [D, V]
+        return out[0] if squeeze else out.T
 
     def sum(self, values: jnp.ndarray) -> jnp.ndarray:
         tiled, squeeze = self._tile_values(values, 0)
         out = seg_sum_pallas(tiled, self.lids, self.tbid, self.tfirst,
                              block_size=self.block_size,
-                             num_out_blocks=self.num_out_blocks,
-                             interpret=self.interpret)
-        out = out[:self.num_segments]
-        return out[:, 0] if squeeze else out
+                             num_out_blocks=self.num_out_blocks)
+        return self._finish(out, squeeze)
 
     def _minmax(self, values, is_min):
         dtype = values.dtype
@@ -217,9 +223,8 @@ class BlockedSegmentReducer:
         out = seg_minmax_pallas(tiled, self.lids, self.tbid, self.tfirst,
                                 block_size=self.block_size,
                                 num_out_blocks=self.num_out_blocks,
-                                is_min=is_min, interpret=self.interpret)
-        out = out[:self.num_segments]
-        return out[:, 0] if squeeze else out
+                                is_min=is_min)
+        return self._finish(out, squeeze)
 
     def min(self, values: jnp.ndarray) -> jnp.ndarray:
         return self._minmax(values, True)

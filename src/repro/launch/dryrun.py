@@ -122,10 +122,7 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
     }
     # set_mesh (not `with mesh:`): also installs the ABSTRACT mesh context
     # so in-model shard_map regions (MoE dispatch) see the mesh axes.
-    # Pre-0.5 jax has no set_mesh; `with mesh:` covers the same regions
-    # there because shard_map resolves axes from the physical mesh env.
-    with (jax.sharding.set_mesh(mesh)
-          if hasattr(jax.sharding, "set_mesh") else mesh):
+    with jax.sharding.set_mesh(mesh):
         jitted = jax.jit(cell.step, in_shardings=tuple(shards),
                          donate_argnums=cell.donate)
         lowered = jitted.lower(*args)

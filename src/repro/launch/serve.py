@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.batch import (BatchedEdgeContext, bucket_key,
                               get_graph_batch, run_batch_slice)
 from repro.core.config_space import SystemConfig
@@ -1145,6 +1146,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--slice-len", type=int, default=4)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.algorithms import REGISTRY
     from repro.graph import rmat_batch

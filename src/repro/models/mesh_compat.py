@@ -1,9 +1,7 @@
-"""Version-spanning access to the active abstract mesh.
+"""Access to the active abstract mesh.
 
-``jax.sharding.get_abstract_mesh`` appeared in jax 0.5; earlier versions
-carry the mesh context in ``thread_resources`` (set by ``with mesh:``).
 Model code asks one question — "is a mesh context active, and which?" —
-so expose exactly that and keep the version probing out of model files.
+so expose exactly that.
 """
 from __future__ import annotations
 
@@ -16,10 +14,5 @@ __all__ = ["active_abstract_mesh"]
 
 def active_abstract_mesh() -> Optional["jax.sharding.AbstractMesh"]:
     """The active abstract mesh, or None when no mesh context is set."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        am = getter()
-        return None if am is None or am.empty else am
-    from jax._src import mesh as _mesh_lib  # pre-0.5 fallback
-    phys = _mesh_lib.thread_resources.env.physical_mesh
-    return None if phys.empty else phys.abstract_mesh
+    am = jax.sharding.get_abstract_mesh()
+    return None if am is None or am.empty else am

@@ -115,3 +115,68 @@ class TestPallasPath:
         ref = oracle(tiny_graph)
         mask = np.isfinite(ref)
         assert np.allclose(got[mask], ref[mask], atol=1e-4)
+
+
+class TestVectorizedOracles:
+    """The numpy oracles equal the per-edge loop forms they replaced."""
+
+    @staticmethod
+    def _loop_refs(g, source):
+        v = g.n_nodes
+        src, dst = np.asarray(g.src, np.int64), np.asarray(g.dst, np.int64)
+        row_ptr = np.asarray(g.row_ptr_out, np.int64)
+        depth = np.full(v, -1, np.int32)
+        depth[source] = 0
+        frontier = [source]
+        while frontier:
+            nxt = []
+            for u in frontier:
+                for e in range(row_ptr[u], row_ptr[u + 1]):
+                    if depth[dst[e]] == -1:
+                        depth[dst[e]] = depth[u] + 1
+                        nxt.append(dst[e])
+            frontier = nxt
+        w = np.asarray(g.weight, np.float64)
+        dist = np.full(v, np.inf)
+        dist[source] = 0.0
+        for _ in range(v):
+            new = dist.copy()
+            np.minimum.at(new, dst, dist[src] + w)
+            if np.array_equal(new, dist):
+                break
+            dist = new
+        label = np.arange(v)
+        while True:
+            new = label.copy()
+            np.minimum.at(new, dst, label[src])
+            np.minimum.at(new, src, label[dst])
+            new = new[new]
+            if np.array_equal(new, label):
+                break
+            label = new
+        inv = 1.0 / np.maximum(np.asarray(g.out_degree, np.float64), 1)
+        dangling = np.asarray(g.out_degree) == 0
+        rank = np.full(v, 1.0 / v)
+        for _ in range(256):
+            contrib = np.zeros(v)
+            np.add.at(contrib, dst, rank[src] * inv[src])
+            new = (1 - 0.85) / v + 0.85 * (contrib + rank[dangling].sum() / v)
+            done = np.abs(new - rank).sum() < 1e-6
+            rank = new
+            if done:
+                break
+        return {"bfs": depth, "sssp": dist.astype(np.float32),
+                "cc": label.astype(np.int32),
+                "pr": rank.astype(np.float32)}
+
+    @pytest.mark.parametrize("graph", ["rmat", "regular"])
+    def test_equal_to_loop_forms(self, graph):
+        from repro.algorithms.reference import bfs_np
+        from repro.graph import regular_graph, rmat_graph
+        g = (rmat_graph(10, 8, seed=2, weighted=True) if graph == "rmat"
+             else regular_graph(300, 2, locality=0.1, seed=4, weighted=True))
+        refs = self._loop_refs(g, source=3)
+        np.testing.assert_array_equal(bfs_np(g, 3), refs["bfs"])
+        np.testing.assert_array_equal(sssp_np(g, 3), refs["sssp"])
+        np.testing.assert_array_equal(cc_np(g), refs["cc"])
+        np.testing.assert_array_equal(pagerank_np(g), refs["pr"])

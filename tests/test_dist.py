@@ -105,7 +105,8 @@ def test_lm_sharded_train_step_runs():
         from repro.data.synthetic import lm_batch
         import dataclasses
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(n_data=4, n_model=2)
         ax = axes_for_mesh(mesh)
         arch = get_arch("starcoder2-7b", axes=ax)
         cfg = dataclasses.replace(arch.reduced_cfg, dp_axes=("data",),

@@ -155,6 +155,34 @@ class TestDispatchCount:
         assert STATS.dispatches == 1
 
 
+@pytest.mark.parametrize("runner", ["fused", "host", "checkpointed",
+                                    "batch"])
+def test_runners_take_graph_arrays_as_arguments(runner):
+    """No runner compiles the graph's edge arrays into its executable:
+    JAX warns about every lowering that captures more constant bytes
+    than the threshold, here well under one edge array."""
+    import warnings
+    from repro.core import run_batch
+
+    g = random_graph(512, 8000, seed=7, weighted=True, block_size=128)
+    cfg = SystemConfig.from_name("DD1")
+    before = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 4 * g.n_edges // 2)
+    try:
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            if runner == "batch":
+                run_batch(bfs(), [g, g], cfg, use_pallas=True)
+            else:
+                run(bfs(), g, cfg, use_pallas=True,
+                    engine="host" if runner == "host" else "fused",
+                    checkpoint_every=2 if runner == "checkpointed" else 0)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", before)
+    assert not [w for w in caught if "constants were captured"
+                in str(w.message)]
+
+
 class TestPlanCache:
     def test_repeated_12_cell_construction_hits(self):
         """Binding the same graph to every config twice: the second

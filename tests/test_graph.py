@@ -1,5 +1,10 @@
 """Graph substrate: structure invariants, generators, partitioner, sampler,
 and the executor design-space equivalence property."""
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
@@ -45,6 +50,27 @@ class TestStructure:
         pairs = set(zip(np.asarray(g.src).tolist(),
                         np.asarray(g.dst).tolist()))
         assert all((b, a) in pairs for a, b in pairs)
+
+
+def _paper_graph_digest(hash_seed: str) -> str:
+    code = ("import hashlib, numpy as np\n"
+            "from repro.graph import paper_graph\n"
+            "g = paper_graph('RAJ', scale=16, weighted=True)\n"
+            "h = hashlib.sha256()\n"
+            "for a in (g.src, g.dst, g.weight): h.update(np.asarray(a))\n"
+            "print(h.hexdigest())\n")
+    src = str(Path(__file__).resolve().parent.parent / "src")
+    env = {**os.environ, "PYTHONHASHSEED": hash_seed, "PYTHONPATH": src,
+           "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True, timeout=300)
+    return out.stdout.strip()
+
+
+def test_paper_graph_same_edges_in_every_process():
+    """The Table II stand-ins are seeded from their names without
+    Python's per-process string-hash salt."""
+    assert _paper_graph_digest("1") == _paper_graph_digest("2")
 
 
 class TestPartition:

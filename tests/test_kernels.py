@@ -98,6 +98,17 @@ class TestSegmentReduce:
         assert got.sum() == pytest.approx(vals.sum(), rel=1e-3, abs=1e-3)
 
 
+def test_interpret_mode_follows_the_backend(monkeypatch):
+    from repro.kernels.segment_reduce import kernel
+    assert kernel._interpret(False) is False   # explicit choice wins
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(kernel.jax, "default_backend", lambda: backend)
+        assert kernel._interpret(None) is want
+    monkeypatch.setattr(kernel.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError):
+        kernel._interpret(None)
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("b,hq,hkv,sq,sk,d,causal", [
         (1, 2, 2, 128, 128, 64, True),
