@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran."""
+UNIT = "%"
+
+
+def read(window):
+    trace = window.trace
+    if trace is None or not trace["window_s"]:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
