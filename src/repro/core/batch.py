@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.config_space import SystemConfig, UpdateProp
 from repro.core.executor import (EdgeContext, RunResult, STATS,
                                  _cached_exec_fn, _jit_hoisted,
@@ -490,12 +491,13 @@ class BatchedEdgeContext:
         prop = self.config.prop
         if prop is not UpdateProp.PUSH_PULL:
             return jnp.full((self.B,), prop is UpdateProp.PULL)
-        rows = frontier.reshape(self.B, self.n_q)
-        urows = (unvisited.reshape(self.B, self.n_q)
-                 if unvisited is not None else None)
-        return choose_direction_batch(rows, self._out_deg_rows,
-                                      self.n_edges_b, self.n_nodes_b,
-                                      prev_pull, unvisited=urows)
+        with jax.named_scope(spans.DIRECTION):
+            rows = frontier.reshape(self.B, self.n_q)
+            urows = (unvisited.reshape(self.B, self.n_q)
+                     if unvisited is not None else None)
+            return choose_direction_batch(rows, self._out_deg_rows,
+                                          self.n_edges_b, self.n_nodes_b,
+                                          prev_pull, unvisited=urows)
 
     def dynamic_direction(self, want_pull) -> jnp.ndarray:
         """``[B]`` per-graph flags for an algorithm-chosen direction
@@ -611,18 +613,19 @@ class BatchedEdgeContext:
         pull_b = jnp.asarray(pull, bool)
         if pull_b.ndim == 0:
             pull_b = jnp.broadcast_to(pull_b, (self.B,))
-        mask = phase.frontier(state)
-        rows = mask.reshape(self.B, self.n_q)
-        m_f = jnp.sum(jnp.where(rows, self._out_deg_rows, 0), axis=1)
-        n_f = jnp.sum(rows.astype(jnp.int32), axis=1)
-        fits = (n_f <= self.vcap_b) & (m_f <= self.cap_b)
-        occ = jnp.where(
-            fits,
-            m_f.astype(jnp.float32) / self.cap_b.astype(jnp.float32),
-            dense_occupancy())
-        occ = jnp.where(pull_b, dense_occupancy(), occ)
-        m_pull = jnp.sum(jnp.where(pull_b, m_f, 0))
-        m_push = jnp.sum(jnp.where(pull_b, 0, m_f))
+        with jax.named_scope(spans.FRONTIER):
+            mask = phase.frontier(state)
+            rows = mask.reshape(self.B, self.n_q)
+            m_f = jnp.sum(jnp.where(rows, self._out_deg_rows, 0), axis=1)
+            n_f = jnp.sum(rows.astype(jnp.int32), axis=1)
+            fits = (n_f <= self.vcap_b) & (m_f <= self.cap_b)
+            occ = jnp.where(
+                fits,
+                m_f.astype(jnp.float32) / self.cap_b.astype(jnp.float32),
+                dense_occupancy())
+            occ = jnp.where(pull_b, dense_occupancy(), occ)
+            m_pull = jnp.sum(jnp.where(pull_b, m_f, 0))
+            m_push = jnp.sum(jnp.where(pull_b, 0, m_f))
         out, _ = self.inner.propagate_sparse(
             state, phase, m_pull > m_push, dtype)
         return out, occ

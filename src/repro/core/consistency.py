@@ -23,6 +23,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.core.config_space import Consistency
 from repro.core.vertex_program import Monoid
 
@@ -39,15 +40,18 @@ def scheduled_reduce(chunk_reduce: Callable[[int], jnp.ndarray],
 
     if consistency is Consistency.DRF1:
         def body(carry, idx):
-            return monoid.combine(carry, chunk_reduce(idx)), None
+            part = chunk_reduce(idx)
+            with jax.named_scope(spans.SCHEDULE):
+                return monoid.combine(carry, part), None
         first = chunk_reduce(0)
         out, _ = jax.lax.scan(body, first, jnp.arange(1, n_chunks))
         return out
 
     # DRFrlx: all partials independent, then reorderable combine.
     partials = jax.vmap(chunk_reduce)(jnp.arange(n_chunks))  # [C, V']
-    if monoid.name == "sum":
-        return jnp.sum(partials, axis=0)
-    if monoid.name == "min":
-        return jnp.min(partials, axis=0)
-    return jnp.max(partials, axis=0)
+    with jax.named_scope(spans.SCHEDULE):
+        if monoid.name == "sum":
+            return jnp.sum(partials, axis=0)
+        if monoid.name == "min":
+            return jnp.min(partials, axis=0)
+        return jnp.max(partials, axis=0)

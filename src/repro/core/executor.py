@@ -66,7 +66,9 @@ from typing import Any, Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.core import spans
 from repro.core.coherence import segment_reduce, segment_reduce_owned
 from repro.core.config_space import (Coherence, Consistency, SystemConfig,
                                      UpdateProp)
@@ -192,8 +194,10 @@ class EdgeContext:
             ctx._graph_strong = None
             return ctx
 
-        return PLAN_CACHE.get(
-            graph, "context", (config, bool(use_pallas), cap, mode), build)
+        with TraceAnnotation(spans.CONTEXT):
+            return PLAN_CACHE.get(
+                graph, "context", (config, bool(use_pallas), cap, mode),
+                build)
 
     def __init__(self, graph: Graph, config: SystemConfig,
                  use_pallas: bool = False,
@@ -347,8 +351,10 @@ class EdgeContext:
         prop = self.config.prop
         if prop is not UpdateProp.PUSH_PULL:
             return jnp.asarray(prop is UpdateProp.PULL)
-        return choose_direction(frontier, self._out_degree, self.n_edges,
-                                self.n_nodes, prev_pull, unvisited=unvisited)
+        with jax.named_scope(spans.DIRECTION):
+            return choose_direction(frontier, self._out_degree, self.n_edges,
+                                    self.n_nodes, prev_pull,
+                                    unvisited=unvisited)
 
     def dynamic_direction(self, want_pull) -> jnp.ndarray:
         """An algorithm-chosen direction as this context's traced flag.
@@ -504,15 +510,16 @@ class EdgeContext:
                 dense_occ
 
         def push(st):
-            front = dense_to_sparse(phase.frontier(st),
-                                    self._sparse_vertex_capacity)
-            edges = gather_frontier_edges(front.ids, self._row_ptr_out,
-                                          self.sparse_edge_capacity)
-            fits = ~front.overflowed & ~edges.overflowed
-            occ = jnp.where(
-                fits,
-                edges.count.astype(jnp.float32) / self._cap_e_f32,
-                dense_occ)
+            with jax.named_scope(spans.FRONTIER):
+                front = dense_to_sparse(phase.frontier(st),
+                                        self._sparse_vertex_capacity)
+                edges = gather_frontier_edges(front.ids, self._row_ptr_out,
+                                              self.sparse_edge_capacity)
+                fits = ~front.overflowed & ~edges.overflowed
+                occ = jnp.where(
+                    fits,
+                    edges.count.astype(jnp.float32) / self._cap_e_f32,
+                    dense_occ)
             out = jax.lax.cond(
                 fits,
                 lambda s: self._propagate_gathered(s, phase, edges.edge_ids,
@@ -537,19 +544,21 @@ class EdgeContext:
         chunked schedule.
         """
         src, dst, w = self._csr_raw
-        valid = edge_ids >= 0
-        at = jnp.where(valid, edge_ids, 0)
-        sv, tv, wv = src[at], dst[at], w[at]
-        keep = valid
-        if phase.spred is not None:
-            keep &= phase.spred(state, sv)
-        if phase.tpred is not None:
-            keep &= phase.tpred(state, tv)
-        msg = phase.vprop(state, sv, wv).astype(dtype)
-        ids = jnp.where(keep, tv, -1)
-        return gathered_segment_reduce(msg, ids, self.n_nodes,
-                                       phase.monoid.name,
-                                       plan=self._gather_plan)
+        with jax.named_scope(spans.EDGE_GATHER):
+            valid = edge_ids >= 0
+            at = jnp.where(valid, edge_ids, 0)
+            sv, tv, wv = src[at], dst[at], w[at]
+            keep = valid
+            if phase.spred is not None:
+                keep &= phase.spred(state, sv)
+            if phase.tpred is not None:
+                keep &= phase.tpred(state, tv)
+            msg = phase.vprop(state, sv, wv).astype(dtype)
+            ids = jnp.where(keep, tv, -1)
+        with jax.named_scope(spans.EDGE_REDUCE):
+            return gathered_segment_reduce(msg, ids, self.n_nodes,
+                                           phase.monoid.name,
+                                           plan=self._gather_plan)
 
     def _propagate(self, state, phase: EdgePhase, direction: UpdateProp,
                    dtype) -> jnp.ndarray:
@@ -567,40 +576,44 @@ class EdgeContext:
             # pull); masked edges contribute the monoid identity,
             # kernel-internal DMA pipelining plays the consistency role.
             so, do, wo = self._pull_raw if pull else self._owned_raw
-            mask = jnp.ones(so.shape, bool)
-            if phase.spred is not None:
-                mask &= phase.spred(state, so)
-            if phase.tpred is not None:
-                mask &= phase.tpred(state, do)
-            msg = phase.vprop(state, so, wo).astype(dtype)
-            return reducer.masked(msg, mask, monoid.name, ident=ident)
+            with jax.named_scope(spans.EDGE_GATHER):
+                mask = jnp.ones(so.shape, bool)
+                if phase.spred is not None:
+                    mask &= phase.spred(state, so)
+                if phase.tpred is not None:
+                    mask &= phase.tpred(state, do)
+                msg = phase.vprop(state, so, wo).astype(dtype)
+            with jax.named_scope(spans.EDGE_REDUCE):
+                return reducer.masked(msg, mask, monoid.name, ident=ident)
 
         def chunk_reduce(i):
-            src = jax.lax.dynamic_index_in_dim(src_c, i, keepdims=False)
-            dst = jax.lax.dynamic_index_in_dim(dst_c, i, keepdims=False)
-            w = jax.lax.dynamic_index_in_dim(w_c, i, keepdims=False)
-            sv = jnp.minimum(src, v - 1)
-            tv = jnp.minimum(dst, v - 1)
-            mask = (src < v) & (dst < v)
-            if phase.spred is not None:
-                mask &= phase.spred(state, sv)
-            if phase.tpred is not None:
-                mask &= phase.tpred(state, tv)
-            msg = phase.vprop(state, sv, w).astype(dtype)
-            msg = jnp.where(mask, msg, ident)
-            if pull:
-                # by-dst order: sorted ids -> dense local (non-atomic)
-                # update (chunks of a sorted array stay sorted).  Keep
-                # ids = dst — rewriting masked ids to the sentinel would
+            with jax.named_scope(spans.EDGE_GATHER):
+                src = jax.lax.dynamic_index_in_dim(src_c, i, keepdims=False)
+                dst = jax.lax.dynamic_index_in_dim(dst_c, i, keepdims=False)
+                w = jax.lax.dynamic_index_in_dim(w_c, i, keepdims=False)
+                sv = jnp.minimum(src, v - 1)
+                tv = jnp.minimum(dst, v - 1)
+                mask = (src < v) & (dst < v)
+                if phase.spred is not None:
+                    mask &= phase.spred(state, sv)
+                if phase.tpred is not None:
+                    mask &= phase.tpred(state, tv)
+                msg = phase.vprop(state, sv, w).astype(dtype)
+                msg = jnp.where(mask, msg, ident)
+                # by-dst order keeps ids = dst: sorted ids -> dense local
+                # (non-atomic) update, and chunks of a sorted array stay
+                # sorted.  Rewriting masked ids to the sentinel would
                 # break the sorted invariant the flag asserts; masked
                 # edges already carry the identity, which no-ops in the
                 # combine, and padding edges carry dst = v themselves.
-                return segment_reduce(msg, dst, v + 1, monoid,
-                                      indices_are_sorted=True)
-            ids = jnp.where(mask, dst, v)
-            if cfg.coherence is Coherence.DENOVO:
-                return segment_reduce_owned(msg, ids, v + 1, monoid)
-            return segment_reduce(msg, ids, v + 1, monoid)
+                ids = dst if pull else jnp.where(mask, dst, v)
+            with jax.named_scope(spans.EDGE_REDUCE):
+                if pull:
+                    return segment_reduce(msg, ids, v + 1, monoid,
+                                          indices_are_sorted=True)
+                if cfg.coherence is Coherence.DENOVO:
+                    return segment_reduce_owned(msg, ids, v + 1, monoid)
+                return segment_reduce(msg, ids, v + 1, monoid)
 
         out = scheduled_reduce(chunk_reduce, self.n_chunks,
                                cfg.consistency, monoid)
@@ -721,7 +734,8 @@ def _jit_hoisted(fn: Callable, args: tuple, donate_argnums: tuple = (),
     takes the same arguments as ``fn`` (``compile=True`` compiles it
     ahead of time for those arguments' shapes).
     """
-    closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args)
+    with TraceAnnotation(spans.TRACE):
+        closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args)
     consts = [jnp.asarray(c) for c in closed.consts]
     out_tree = jax.tree.structure(out_shape)
 
@@ -732,7 +746,8 @@ def _jit_hoisted(fn: Callable, args: tuple, donate_argnums: tuple = (),
     jitted = jax.jit(call,
                      donate_argnums=tuple(i + 1 for i in donate_argnums))
     if compile:
-        jitted = jitted.lower(consts, *args).compile()
+        with TraceAnnotation(spans.COMPILE):
+            jitted = jitted.lower(consts, *args).compile()
     return partial(jitted, consts)
 
 
@@ -814,8 +829,9 @@ def _run_fused(program: VertexProgram, ctx: EdgeContext, state,
 
         def body(carry):
             st, it, done, db, ob = carry
-            new = program.step(ctx, st, it)
-            done = program.converged(st, new)
+            with jax.named_scope(spans.VERTEX_STEP):
+                new = program.step(ctx, st, it)
+                done = program.converged(st, new)
             if traced:
                 db = jax.lax.dynamic_update_index_in_dim(
                     db, jnp.asarray(new[FRONTIER_DIR_KEY], bool), it, 0)
@@ -842,19 +858,23 @@ def _run_fused(program: VertexProgram, ctx: EdgeContext, state,
                          ("fused", limit, traced, occ_traced), build)
     t0 = time.perf_counter()
     STATS.dispatches += 1
-    state, it_dev, done_dev, dir_buf, occ_buf = fn(state, dir_buf, occ_buf)
-    jax.block_until_ready((state, it_dev, done_dev, dir_buf, occ_buf))
+    with TraceAnnotation(spans.DISPATCH):
+        state, it_dev, done_dev, dir_buf, occ_buf = fn(state, dir_buf,
+                                                       occ_buf)
+    with TraceAnnotation(spans.WAIT):
+        jax.block_until_ready((state, it_dev, done_dev, dir_buf, occ_buf))
     dt = time.perf_counter() - t0
     # the run's single host sync is above; everything below is decoding
-    it = int(it_dev)
-    done = bool(done_dev)
-    trace = None
-    occ_trace = None
-    if traced:
-        trace = "".join("T" if b else "S"
-                        for b in np.asarray(dir_buf)[:it])
-    if occ_traced:
-        occ_trace = [float(o) for o in np.asarray(occ_buf)[:it]]
+    with TraceAnnotation(spans.DECODE):
+        it = int(it_dev)
+        done = bool(done_dev)
+        trace = None
+        occ_trace = None
+        if traced:
+            trace = "".join("T" if b else "S"
+                            for b in np.asarray(dir_buf)[:it])
+        if occ_traced:
+            occ_trace = [float(o) for o in np.asarray(occ_buf)[:it]]
     return RunResult(state=state, iterations=it, seconds=dt, converged=done,
                      direction_trace=trace, occupancy_trace=occ_trace,
                      engine="fused", dispatches=1)
@@ -920,34 +940,37 @@ def run(program: VertexProgram, graph: Graph, config: SystemConfig,
     if engine not in ("fused", "host"):
         raise ValueError(f"unknown engine {engine!r}; "
                          "expected 'fused' or 'host'")
-    config_source = "caller"
-    if specialize not in (None, False, "off"):
-        from repro.core.specialize_learned import resolve_config
-        config, config_source = resolve_config(program, graph, config,
-                                               specialize)
-    if (checkpoint_every or retry is not None or fault_injector is not None
-            or checkpoint_dir is not None):
-        from repro.core.resilience import run_resilient
-        res = run_resilient(
-            program, graph, config, key=key, max_iters=max_iters,
-            use_pallas=use_pallas, warmup=warmup,
-            sparse_edge_capacity=sparse_edge_capacity, engine=engine,
-            autotune=autotune, checkpoint_every=checkpoint_every,
-            retry=retry, sentinels=sentinels,
-            ring_capacity=ring_capacity, fault_injector=fault_injector,
-            checkpoint_dir=checkpoint_dir)
-    else:
-        ctx = EdgeContext.create(graph, config, use_pallas=use_pallas,
-                                 sparse_edge_capacity=sparse_edge_capacity,
-                                 autotune=autotune)
-        state = program.init(graph, key) if key is not None \
-            else program.init(graph)
-        state = jax.tree.map(jnp.asarray, state)
-        limit = max_iters or program.max_iters
-        runner = _run_fused if engine == "fused" else _run_host
-        res = runner(program, ctx, state, limit, warmup)
-    res.config_name = config.name
-    res.config_source = config_source
+    with TraceAnnotation(spans.RUN):
+        config_source = "caller"
+        if specialize not in (None, False, "off"):
+            from repro.core.specialize_learned import resolve_config
+            config, config_source = resolve_config(program, graph, config,
+                                                   specialize)
+        if (checkpoint_every or retry is not None
+                or fault_injector is not None or checkpoint_dir is not None):
+            from repro.core.resilience import run_resilient
+            res = run_resilient(
+                program, graph, config, key=key, max_iters=max_iters,
+                use_pallas=use_pallas, warmup=warmup,
+                sparse_edge_capacity=sparse_edge_capacity, engine=engine,
+                autotune=autotune, checkpoint_every=checkpoint_every,
+                retry=retry, sentinels=sentinels,
+                ring_capacity=ring_capacity, fault_injector=fault_injector,
+                checkpoint_dir=checkpoint_dir)
+        else:
+            ctx = EdgeContext.create(
+                graph, config, use_pallas=use_pallas,
+                sparse_edge_capacity=sparse_edge_capacity,
+                autotune=autotune)
+            with TraceAnnotation(spans.INIT):
+                state = program.init(graph, key) if key is not None \
+                    else program.init(graph)
+                state = jax.tree.map(jnp.asarray, state)
+            limit = max_iters or program.max_iters
+            runner = _run_fused if engine == "fused" else _run_host
+            res = runner(program, ctx, state, limit, warmup)
+        res.config_name = config.name
+        res.config_source = config_source
     return res
 
 
