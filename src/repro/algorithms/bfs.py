@@ -103,4 +103,6 @@ def bfs(source: int = 0, max_iters: int = 4096) -> VertexProgram:
         frontier_update=lambda st: st["active"],
         sentinels=sentinels,
         certificate=certificate,
+        # source is read by init, frontier_init and certificate alone
+        runner_key=(max_iters,),
     )

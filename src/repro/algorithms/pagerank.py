@@ -85,4 +85,5 @@ def pagerank(damping: float = 0.85, tol: float = 1e-6,
         # the proof (perturbations are re-absorbed, not frozen in).
         sentinels={"rank_range": lambda p, c: jnp.all(
             (c["rank"] >= 0.0) & (c["rank"] <= 1.0 + 1e-3))},
+        runner_key=(damping, tol, max_iters),
     )

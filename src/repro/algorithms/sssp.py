@@ -80,4 +80,6 @@ def sssp(source: int = 0, max_iters: int = 4096) -> VertexProgram:
         sentinels={"dist_nonnegative":
                    lambda p, c: jnp.all(c["dist"] >= 0.0)},
         certificate=certificate,
+        # source is read by init, frontier_init and certificate alone
+        runner_key=(max_iters,),
     )

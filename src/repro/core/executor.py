@@ -704,15 +704,20 @@ def _cached_exec_fn(program: VertexProgram, ctx: EdgeContext,
     A fresh ``jax.jit`` closure per ``run`` call would miss jax's jit
     cache every time, recompiling the step (host) or the entire fused
     while_loop per repeat of a sweep — usually the dominant sweep cost.
-    Entries are keyed on ``id(program)`` plus the context/engine params
-    and hold the program strongly, so a program id can never be
-    recycled while its entry is alive; entries die with the graph, and
-    the bucket is LRU-bounded so a stream of distinct program instances
-    on one long-lived graph (e.g. exact BC looping over roots) cannot
-    accumulate unbounded compiled executables.
+    Entries are keyed on what the runner is built from: the program's
+    ``(name, runner_key)`` when it declares one, so every BFS root
+    shares one runner, else ``id(program)``; plus the context/engine
+    params.  An entry holds the program it was first built from
+    strongly, so a program id can never be recycled while its entry is
+    alive; entries die with the graph, and the bucket is LRU-bounded so
+    a stream of distinct program instances on one long-lived graph
+    (e.g. exact BC looping over roots) cannot accumulate unbounded
+    compiled executables.
     """
     g = ctx.graph
-    key = (id(program), ctx.config, ctx.use_pallas,
+    runner = (id(program) if program.runner_key is None
+              else (program.name, program.runner_key))
+    key = (runner, ctx.config, ctx.use_pallas,
            ctx.sparse_edge_capacity, ctx.plan_signature) + params
     if g is None:  # graph already collected; nothing to key on
         return build()[1]
@@ -805,22 +810,10 @@ def _run_host(program: VertexProgram, ctx: EdgeContext, state,
                      engine="host", dispatches=it)
 
 
-def _run_fused(program: VertexProgram, ctx: EdgeContext, state,
-               limit: int, warmup: bool) -> RunResult:
-    """Device-resident engine: the whole convergence loop is one jitted
-    ``lax.while_loop`` dispatch with one host sync at the end.
-
-    Carry layout: ``(state, it, done, dir_buf, occ_buf)``.  The trace
-    buffers are preallocated ``[limit]`` device arrays the body writes
-    at index ``it`` via ``lax.dynamic_update_index_in_dim``; after the
-    loop the first ``it`` entries decode to the same
-    ``direction_trace``/``occupancy_trace`` strings/lists the host
-    engine produces, preserving the frontier protocol bit for bit.
-    """
-    traced, occ_traced = _trace_flags(program, state)
-    dir_buf = jnp.zeros((limit,), bool) if traced else None
-    occ_buf = (jnp.full((limit,), dense_occupancy())
-               if occ_traced else None)
+def _fused_loop(program: VertexProgram, ctx: EdgeContext, limit: int,
+                traced: bool, occ_traced: bool) -> Callable:
+    """The fused engine's ``(state, dir_buf, occ_buf)`` convergence loop,
+    the function its runner is traced and compiled from."""
 
     def fused(st, db, ob):
         def cond(carry):
@@ -845,10 +838,31 @@ def _run_fused(program: VertexProgram, ctx: EdgeContext, state,
             cond, body,
             (st, jnp.int32(0), jnp.asarray(False), db, ob))
 
+    return fused
+
+
+def _run_fused(program: VertexProgram, ctx: EdgeContext, state,
+               limit: int, warmup: bool) -> RunResult:
+    """Device-resident engine: the whole convergence loop is one jitted
+    ``lax.while_loop`` dispatch with one host sync at the end.
+
+    Carry layout: ``(state, it, done, dir_buf, occ_buf)``.  The trace
+    buffers are preallocated ``[limit]`` device arrays the body writes
+    at index ``it`` via ``lax.dynamic_update_index_in_dim``; after the
+    loop the first ``it`` entries decode to the same
+    ``direction_trace``/``occupancy_trace`` strings/lists the host
+    engine produces, preserving the frontier protocol bit for bit.
+    """
+    traced, occ_traced = _trace_flags(program, state)
+    dir_buf = jnp.zeros((limit,), bool) if traced else None
+    occ_buf = (jnp.full((limit,), dense_occupancy())
+               if occ_traced else None)
+    fused = _fused_loop(program, ctx, limit, traced, occ_traced)
+
     def build():
         # warmup AOT-compiles outside the timed region; unlike the host
         # engine's run-one-step warmup this executes nothing on device.
-        # The compiled executable is cached per (program, context,
+        # The compiled executable is cached per (runner, context,
         # limit) so sweep repeats skip the while_loop compile entirely.
         return program, _jit_hoisted(fused, (state, dir_buf, occ_buf),
                                      donate_argnums=(0, 1, 2),

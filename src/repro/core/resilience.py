@@ -289,7 +289,9 @@ def check_certificate(program: VertexProgram, ctx: EdgeContext,
 
     Returns None when the program declares no certificate, else the
     proof's verdict.  The jitted evaluator is plan-cached per
-    (program, context) like every other compiled runner.
+    (program instance, context): a certificate may read parameters
+    outside the program's ``runner_key`` (BFS's and SSSP's ``source``),
+    so it is never shared between instances.
     """
     if program.certificate is None:
         return None
@@ -300,7 +302,7 @@ def check_certificate(program: VertexProgram, ctx: EdgeContext,
         return program, _jit_hoisted(lambda st: jnp.asarray(
             program.certificate(ctx, st), bool).reshape(()), (state,))
 
-    fn = _cached_exec_fn(program, ctx, ("certificate",), build)
+    fn = _cached_exec_fn(program, ctx, ("certificate", id(program)), build)
     return bool(fn(state))
 
 

@@ -11,7 +11,7 @@ edge-propagated updates execute.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Hashable, Optional
 
 import jax.numpy as jnp
 
@@ -156,6 +156,14 @@ class VertexProgram:
     on *converged* states, which catches corruptions (e.g. dropped
     updates that revert a vertex to an older-but-plausible value) that
     boundary sentinels structurally cannot see.
+
+    Runner sharing (optional): ``runner_key`` names the parameters that
+    ``step``, ``converged``, ``frontier_update`` and ``sentinels`` close
+    over.  Two programs with the same ``name`` and ``runner_key`` must
+    trace to the same runner, so they share one compiled executable: a
+    parameter read only by ``init``, ``frontier_init``, ``extract`` or
+    ``certificate`` (BFS's ``source``) stays out of the key.  ``None``
+    (the default) gives every instance a runner of its own.
     """
     name: str
     init: Callable[..., State]                     # (graph[, key]) -> state
@@ -171,6 +179,7 @@ class VertexProgram:
     monotone: Optional[dict] = None                # key -> ordering direction
     sentinels: Optional[dict] = None               # name -> (prev, cur) -> ok
     certificate: Optional[Callable] = None         # (ctx, state) -> bool
+    runner_key: Optional[Hashable] = None          # shared-runner params
 
     @property
     def properties(self) -> AlgorithmicProperties:

@@ -253,11 +253,14 @@ class TestPlanCache:
         assert warm_wall < 5.0  # no multi-second recompile on repeat
 
     def test_distinct_programs_get_distinct_runners(self, rand_g):
-        """Two program instances must not share a compiled runner even
-        on the same cell (the cache pins each program by identity)."""
+        """Two instances of a program that declares no runner_key must
+        not share a compiled runner even on the same cell (the cache
+        pins each program by identity)."""
         cfg = SystemConfig.from_name("SG1")
-        a = run(bfs(source=0), rand_g, cfg, engine="fused")
-        b = run(bfs(source=1), rand_g, cfg, engine="fused")
+        misses = PLAN_CACHE.kind_stats("exec_fn")["misses"]
+        a = run(bc(root=0), rand_g, cfg, engine="fused")
+        b = run(bc(root=1), rand_g, cfg, engine="fused")
+        assert PLAN_CACHE.kind_stats("exec_fn")["misses"] == misses + 2
         assert int(np.asarray(a.state["depth"])[0]) == 0
         assert int(np.asarray(b.state["depth"])[1]) == 0
 
@@ -269,7 +272,7 @@ class TestPlanCache:
         PLAN_CACHE.clear()
         cfg = SystemConfig.from_name("SG1")
         for src in range(executor._EXEC_FN_CAPACITY + 8):
-            run(bfs(source=src % rand_g.n_nodes), rand_g, cfg,
+            run(bc(root=src % rand_g.n_nodes), rand_g, cfg,
                 max_iters=1, engine="fused")
         with PLAN_CACHE._lock:
             n_exec = sum(1 for k in PLAN_CACHE._store
