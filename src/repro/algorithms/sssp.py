@@ -7,6 +7,11 @@ dynamic configs' per-iteration direction: no monotone "unvisited" set
 exists (re-relaxations can reactivate settled vertices), so the push->pull
 trigger is the frontier-edge-density fallback of
 :func:`repro.core.frontier.choose_direction`.
+
+The state counts vertex activations (``activations``: the source, then
+every vertex whose distance improved, once per improvement), which the
+program declares as a counter.  Over the vertices reached, it says how
+often label-correcting relaxation re-activates a settled vertex.
 """
 from __future__ import annotations
 
@@ -33,6 +38,7 @@ def sssp(source: int = 0, max_iters: int = 4096) -> VertexProgram:
         dist = jnp.full((v,), jnp.inf, jnp.float32).at[source].set(0.0)
         active = jnp.zeros((v,), bool).at[source].set(True)
         return {"dist": dist, "active": active,
+                "activations": jnp.int32(1),
                 FRONTIER_DIR_KEY: jnp.asarray(False),
                 FRONTIER_OCC_KEY: dense_occupancy()}
 
@@ -41,8 +47,10 @@ def sssp(source: int = 0, max_iters: int = 4096) -> VertexProgram:
         cand, occ = ctx.propagate_sparse(st, phase, pull)
         dist = jnp.minimum(st["dist"], cand)
         active = dist < st["dist"]
-        return {"dist": dist, "active": active, FRONTIER_DIR_KEY: pull,
-                FRONTIER_OCC_KEY: occ}
+        activations = (st["activations"]
+                       + ctx.per_graph_sum(active).astype(jnp.int32))
+        return {"dist": dist, "active": active, "activations": activations,
+                FRONTIER_DIR_KEY: pull, FRONTIER_OCC_KEY: occ}
 
     def converged(prev, cur):
         return ~jnp.any(cur["active"])
@@ -82,4 +90,5 @@ def sssp(source: int = 0, max_iters: int = 4096) -> VertexProgram:
         certificate=certificate,
         # source is read by init, frontier_init and certificate alone
         runner_key=(max_iters,),
+        counters={"activations": lambda st: st["activations"]},
     )

@@ -663,6 +663,10 @@ class RunResult:
     #: or "learned" (the trained model) — see
     #: :func:`repro.core.specialize_learned.resolve_config`.
     config_source: str = "caller"
+    #: The program's declared counters (``VertexProgram.counters``), read
+    #: from the final state as Python ints; None for programs that
+    #: declare none.
+    counters: Optional[dict] = None
 
     def __post_init__(self):
         if self.outcome is None:
@@ -695,6 +699,17 @@ def _trace_flags(program: VertexProgram, state) -> tuple:
               and isinstance(state, dict) and FRONTIER_DIR_KEY in state)
     occ_traced = traced and FRONTIER_OCC_KEY in state
     return traced, occ_traced
+
+
+def _read_counters(program: VertexProgram, state) -> Optional[dict]:
+    """The program's counters read from a final ``state`` whose run has
+    already synced: one transfer of scalars the loop computed."""
+    if not program.counters:
+        return None
+    with TraceAnnotation(spans.DECODE):
+        got = jax.device_get({name: fn(state)
+                              for name, fn in program.counters.items()})
+    return {name: int(v) for name, v in got.items()}
 
 
 def _cached_exec_fn(program: VertexProgram, ctx: EdgeContext,
@@ -983,6 +998,7 @@ def run(program: VertexProgram, graph: Graph, config: SystemConfig,
             limit = max_iters or program.max_iters
             runner = _run_fused if engine == "fused" else _run_host
             res = runner(program, ctx, state, limit, warmup)
+        res.counters = _read_counters(program, res.state)
         res.config_name = config.name
         res.config_source = config_source
     return res
@@ -1069,6 +1085,7 @@ def run_batch(program: VertexProgram, graphs, config: SystemConfig,
             packed = batch.pack_state(states, pad=program.state_pad)
             for i, r in zip(part, run_fused_batch(program, batch, bctx,
                                                   packed, limit, warmup)):
+                r.counters = _read_counters(program, r.state)
                 r.config_name = group_config.name
                 r.config_source = resolved[i][1]
                 results[i] = r

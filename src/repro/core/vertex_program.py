@@ -164,6 +164,15 @@ class VertexProgram:
     parameter read only by ``init``, ``frontier_init``, ``extract`` or
     ``certificate`` (BFS's ``source``) stays out of the key.  ``None``
     (the default) gives every instance a runner of its own.
+
+    Counters (optional): ``counters`` maps a name to a function
+    ``(final state) -> integer scalar`` that reads a count the program
+    keeps in its own state, such as how many vertex activations a run
+    made.  ``run`` and ``run_batch`` read each into
+    :attr:`~repro.core.executor.RunResult.counters` as a Python int after
+    the run's results are on the host, so a counter adds no device work
+    beyond the state update that keeps it.  ``None`` (the default)
+    declares none, and leaves the runner exactly as it was.
     """
     name: str
     init: Callable[..., State]                     # (graph[, key]) -> state
@@ -180,6 +189,7 @@ class VertexProgram:
     sentinels: Optional[dict] = None               # name -> (prev, cur) -> ok
     certificate: Optional[Callable] = None         # (ctx, state) -> bool
     runner_key: Optional[Hashable] = None          # shared-runner params
+    counters: Optional[dict] = None                # name -> (state) -> int
 
     @property
     def properties(self) -> AlgorithmicProperties:
